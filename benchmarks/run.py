@@ -54,6 +54,7 @@ def main() -> None:
                 for row in rows:
                     print(row)
         except Exception:  # noqa: BLE001
+            failed += 1
             traceback.print_exc()
     if args.engine_json:
         try:

@@ -131,6 +131,7 @@ class ExecutionBackend:
                                  comm_bytes=nbytes, collective=op.collective,
                                  n_nodes=n)
 
+        wrapped.__wrapped__ = fn       # the compiled program, for inspection
         return wrapped
 
     # ------------------------------------------------------------- lowering
@@ -248,8 +249,13 @@ class ExecutionBackend:
                 lambda w, d: (w.astype(jnp.float32) + d).astype(w.dtype),
                 W, delta)
 
-        donate = (0, 1) if jax.default_backend() in ("tpu", "gpu") else ()
-        return jax.jit(apply, donate_argnums=donate)
+        return jax.jit(apply, donate_argnums=donated(0, 1))
+
+
+def donated(*argnums: int):
+    """``donate_argnums`` where donation is real (TPU/GPU); the CPU
+    backend ignores donation and warns, so it gets none."""
+    return argnums if jax.default_backend() in ("tpu", "gpu") else ()
 
 
 # ---------------------------------------------------------------------------

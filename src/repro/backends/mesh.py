@@ -16,26 +16,31 @@ Two **placements** decide what one replica is (DESIGN.md §5):
 
 * ``replica_ddp`` (default) — each replica is a whole-model copy; the
   leading replica axis is the only sharded dim and every program is a
-  fully-manual ``shard_map`` over the replica axes.
+  fully-manual ``jax.shard_map`` over every mesh axis.
 * ``replica_tp``  — one replica *spans* the mesh's ``model`` axis: inner
   parameter dims shard with the megatron-style ``base_spec`` rules from
   ``launch/sharding.py`` (column/row-parallel matmuls, vocab-parallel
   embeddings), threaded through ``put_params``/``put_opt`` and pinned on
   program outputs.  Programs become *partial-manual* ``shard_map``s:
-  manual over the replica axes (``data``/``pod``) so the replica-axis
-  collectives stay explicit ``lax.pmean``/``psum``, while the ``model``
-  axis is left to GSPMD (``auto={'model'}``), which inserts the
-  intra-replica tensor-parallel collectives where the matmuls need them.
+  ``axis_names`` holds only the replica axes (``data``/``pod``), so the
+  replica-axis collectives stay explicit ``lax.pmean``/``psum``, while the
+  ``model`` axis is left to GSPMD, which inserts the intra-replica
+  tensor-parallel collectives where the matmuls need them.  The two QSGD
+  programs are the exception: they run fully manual under both placements
+  (see ``_lower_qsgd_step``).
 
 Cross-replica syncs are identical under both placements — the replica mean
 is elementwise, so it never needs a model-axis exchange.  Checkpoints are
 placement-neutral: ``device_get`` gathers to host arrays and the restoring
 backend re-``put``s them under its own placement.
 
-On this CPU container the mesh is whatever ``XLA_FLAGS=
---xla_force_host_platform_device_count=N`` provides (tests force 8, split
-4 data x 2 model for ``replica_tp``); on a TPU pod the same code takes
-``launch/mesh.py``'s production mesh.
+Every mesh axis must be in GSPMD's ``Auto`` mode; a mesh built with
+``jax.make_mesh``'s default ``Explicit`` axes is re-declared ``Auto`` on
+the same devices (``launch/mesh.auto_axes``).  On the CPU the mesh is
+whatever ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` provides
+(tests force 8, split 4 data x 2 model for ``replica_tp``); on TPU the
+same code takes the chips JAX sees, or ``launch/mesh.py``'s production
+mesh.
 """
 from __future__ import annotations
 
@@ -44,7 +49,6 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.backends.base import ExecutionBackend, register_backend
@@ -96,6 +100,8 @@ class MeshBackend(ExecutionBackend):
                 model_parallel = 2 if (placement == "replica_tp"
                                        and n > 1 and n % 2 == 0) else 1
             mesh = mesh_mod.make_host_mesh(model_parallel)
+        if isinstance(mesh, Mesh):
+            mesh = mesh_mod.auto_axes(mesh)
         self.mesh = mesh
         self.placement = placement
         sizes = dict(mesh.shape)
@@ -120,12 +126,12 @@ class MeshBackend(ExecutionBackend):
         self._plan = ParallelismPlan(
             plan="replica_dp" if placement == "replica_tp" else "replica_ddp",
             placement=placement)
-        # partial-manual shard_map: manual over the replica axes, every
-        # other mesh axis (the 'model' axis) left to GSPMD
-        self._auto = (frozenset(set(mesh.axis_names) - set(self.replica_axes))
-                      if placement == "replica_tp" else frozenset())
+        # shard_map's manual axes: replica_tp is manual over the replica
+        # axes only and leaves every other mesh axis ('model') to GSPMD;
+        # replica_ddp is manual over the whole mesh
+        self._manual = frozenset(self.replica_axes if placement == "replica_tp"
+                                 else mesh.axis_names)
         self._cache: Dict[Any, Any] = {}
-        self._ridx = None              # cached global replica-index array
 
     # ------------------------------------------------------------- topology
     def bind(self, n_replicas: int) -> None:
@@ -215,30 +221,17 @@ class MeshBackend(ExecutionBackend):
         return fn
 
     def _shmap(self, chunk, in_specs, out_specs, out_shardings=None, *,
-               auto=None):
-        """``auto=None`` takes the placement's default (partial-manual with
-        GSPMD owning 'model' under replica_tp); pass ``frozenset()`` to
-        force a fully-manual region — required where the body carries an
-        explicit gather collective, which XLA's partitioner rejects inside
-        manual subgroups (same limitation family as PartitionId)."""
-        fn = shard_map(chunk, mesh=self.mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_rep=False,
-                       auto=self._auto if auto is None else auto)
+               manual=None):
+        """``manual=None`` takes the placement's manual axes (the replica
+        axes under replica_tp, GSPMD owning 'model'); pass the full axis
+        set to force a fully-manual region."""
+        fn = jax.shard_map(chunk, mesh=self.mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False,
+                           axis_names=self._manual if manual is None
+                           else manual)
         if out_shardings is not None:
             return jax.jit(fn, out_shardings=out_shardings)
         return jax.jit(fn)
-
-    def _replica_index(self):
-        """Global replica indices (R,), fed to RNG-bearing programs as a
-        stacked operand — each chunk then sees its replicas' global ids.
-        An explicit operand rather than ``lax.axis_index`` because the
-        latter lowers to a PartitionId instruction that GSPMD rejects
-        inside replica_tp's partial-manual (auto 'model') regions.
-        Cached: qsgd_step rides the per-step hot path."""
-        ridx = self._ridx
-        if ridx is None or ridx.shape[0] != self.n_replicas:
-            ridx = self._ridx = jnp.arange(self.n_replicas, dtype=jnp.int32)
-        return ridx
 
     def _pmean(self, x):
         return jax.lax.pmean(x, self.replica_axes)
@@ -258,12 +251,13 @@ class MeshBackend(ExecutionBackend):
                     for x, m in zip(_leaves(W_chunk), _leaves(means)))
         return jax.lax.psum(s_loc, self.replica_axes) / self.n_replicas
 
-    @staticmethod
-    def _local_keys(key, ridx):
-        """Per-replica RNG keys from the chunk's *global* replica indices —
-        the shared ``qsgd.replica_keys`` stream, so it is independent of
-        how replicas map to devices and matches VmapBackend bit-for-bit."""
-        return qsgd_mod.replica_keys(key, ridx)
+    def _local_keys(self, key, n_local: int):
+        """Per-replica RNG keys from the chunk's *global* replica indices
+        (its position on the replica axes times its replica count) — the
+        shared ``qsgd.replica_keys`` stream, so it is independent of how
+        replicas map to devices and matches VmapBackend bit-for-bit."""
+        base = jax.lax.axis_index(self.replica_axes) * n_local
+        return qsgd_mod.replica_keys(key, base + jnp.arange(n_local))
 
     def _metrics_mean(self, metrics: Pytree) -> Pytree:
         """Replica mean of stacked per-replica metrics — a separate tiny
@@ -330,9 +324,9 @@ class MeshBackend(ExecutionBackend):
         bits = op.wire.bits
         grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
 
-        def chunk(Wc, oc, bc, lr, key, ridx):
+        def chunk(Wc, oc, bc, lr, key):
             (loss, aux), grads = jax.vmap(grad_fn)(Wc, bc)
-            keys = self._local_keys(key, ridx)
+            keys = self._local_keys(key, _leaves(Wc)[0].shape[0])
             q = jax.vmap(lambda g, k: qsgd_mod.quantize_pytree(g, k, bits))(
                 grads, keys)
             g_mean = _tm(self._leaf_mean, q)
@@ -345,15 +339,22 @@ class MeshBackend(ExecutionBackend):
             return Wn, on, metrics
 
         def prog(W, opt_state, batch, lr, key):
+            # fully manual under replica_tp too: stochastic rounding is
+            # discontinuous, so a norm or gradient summed across model
+            # shards (rounding unlike the unsharded sum) flips levels and
+            # the trajectory leaves the vmap reference.  Each replica
+            # computes whole on its model devices; the TP layout is pinned
+            # back on the outputs.
             fn = self._cached("qsgd", (W, opt_state, batch), lambda: self._shmap(
                 chunk,
                 (self._stacked(W), self._stacked(opt_state),
-                 self._stacked(batch), P(), P(), P(self._entry)),
+                 self._stacked(batch), P(), P()),
                 (self._stacked(W), self._stacked(opt_state), P()),
                 out_shardings=self._pin(
                     lambda: self._param_shardings(W),
-                    lambda: self._opt_shardings(opt_state, W), None)))
-            return fn(W, opt_state, batch, lr, key, self._replica_index())
+                    lambda: self._opt_shardings(opt_state, W), None),
+                manual=frozenset(self.mesh.axis_names)))
+            return fn(W, opt_state, batch, lr, key)
 
         return prog
 
@@ -465,10 +466,10 @@ class MeshBackend(ExecutionBackend):
         bits = op.wire.bits
         use_kernel = jax.default_backend() == "tpu"
 
-        def chunk(Wc, anchor, key, ridx):
+        def chunk(Wc, anchor, key):
             delta = _tm(lambda w, a: w.astype(jnp.float32) - a[None],
                         Wc, anchor)
-            keys = self._local_keys(key, ridx)
+            keys = self._local_keys(key, _leaves(Wc)[0].shape[0])
             levels, norms = jax.vmap(
                 lambda d, k: qsgd_mod.quantize_split_pytree(
                     d, k, bits, use_kernel=use_kernel))(delta, keys)
@@ -488,21 +489,21 @@ class MeshBackend(ExecutionBackend):
             return Wn, new_anchor, s_k
 
         def prog(W, anchor, key):
-            # fully-manual region even under replica_tp: the partitioner
-            # rejects all_gather inside partial-auto (manual-subgroup)
-            # regions, so the model shards re-materialize at region entry
-            # over the fast intra-replica ICI — the *cross-replica* wire
-            # (the link the paper prices) still carries only int8 levels +
-            # norms, and out_shardings pins the TP layout right back
+            # fully-manual region even under replica_tp, for the reason in
+            # _lower_qsgd_step: per-tensor norms summed across model shards
+            # round unlike vmap's and break the bit-match.  The model
+            # shards re-materialize at region entry over the fast
+            # intra-replica ICI — the *cross-replica* wire (the link the
+            # paper prices) still carries only int8 levels + norms, and
+            # out_shardings pins the TP layout right back
             fn = self._cached("qam", (W, anchor), lambda: self._shmap(
                 chunk,
-                (self._stacked(W), self._replicated(anchor), P(),
-                 P(self._entry)),
+                (self._stacked(W), self._replicated(anchor), P()),
                 (self._stacked(W), self._replicated(anchor), P()),
                 out_shardings=self._pin(
                     lambda: self._param_shardings(W), None, None),
-                auto=frozenset()))
-            return fn(W, anchor, key, self._replica_index())
+                manual=frozenset(self.mesh.axis_names)))
+            return fn(W, anchor, key)
 
         return prog
 

@@ -20,7 +20,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.backends.base import ExecutionBackend, register_backend
+from repro.backends.base import ExecutionBackend, donated, register_backend
 from repro.core import averaging as avg
 from repro.core import qsgd as qsgd_mod
 
@@ -46,8 +46,12 @@ class VmapBackend(ExecutionBackend):
     # resolved by ExecutionBackend.lower(op); every compiled program comes
     # back through timed(op, ...), so a bound clock prices each invocation
     # from the op descriptor (backends/base.py)
+    # W (and the optimizer state of a step) are dead once the program
+    # returns their successors: donating them keeps one copy of the
+    # replica stack on the device, which full-width models need
     def _lower_replica_step(self, op, *, loss_fn, optimizer):
-        return jax.jit(avg.make_local_step(loss_fn, optimizer))
+        return jax.jit(avg.make_local_step(loss_fn, optimizer),
+                       donate_argnums=donated(0, 1))
 
     def _lower_full_step(self, op, *, loss_fn, optimizer):
         return jax.jit(avg.make_full_step(loss_fn, optimizer))
@@ -59,7 +63,8 @@ class VmapBackend(ExecutionBackend):
     def _lower_all_mean(self, op, *, sync_momentum=False):
         use_kernel = self.use_kernel
         return jax.jit(lambda W, o: avg.sync_replicas(
-            W, o, sync_momentum=sync_momentum, use_kernel=use_kernel))
+            W, o, sync_momentum=sync_momentum, use_kernel=use_kernel),
+            donate_argnums=donated(0))
 
     def _lower_inner_mean(self, op):
         g = op.group

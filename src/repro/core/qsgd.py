@@ -31,7 +31,9 @@ def quantize(v: jnp.ndarray, key, bits: int = 8) -> Tuple[jnp.ndarray, jnp.ndarr
     """
     s = (1 << (bits - 1)) - 1
     vf = v.astype(jnp.float32)
-    norm = jnp.sqrt(jnp.sum(jnp.square(vf)))
+    # reduce in the flat logical order: a leaf that arrives in another
+    # physical layout (gathered from model shards) then rounds the same
+    norm = jnp.sqrt(jnp.sum(jnp.square(vf.reshape(-1))))
     scaled = jnp.where(norm > 0, jnp.abs(vf) / norm * s, 0.0)
     floor = jnp.floor(scaled)
     prob = scaled - floor

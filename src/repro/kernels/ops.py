@@ -1,9 +1,10 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On TPU the kernels compile through Mosaic; on this CPU container they run
-in interpret mode (the kernel body executed in python) so the whole system
-works everywhere.  The model code calls these wrappers, never pallas_call
-directly."""
+On TPU the kernels compile through Mosaic, and a kernel the compiler
+refuses raises: there is no fallback to the ``kernels/ref.py`` oracles.
+Off the TPU they run in interpret mode (the kernel body executed in
+python) so the CPU tests exercise the same kernels.  The model code calls
+these wrappers, never pallas_call directly."""
 from __future__ import annotations
 
 import jax

@@ -3,8 +3,12 @@
 Algorithm 2 line 10–11 needs, at every sync, both the replica mean of every
 parameter buffer and S_k = (1/n)·Σ_i ||w̄ − w_i||².  A naive implementation
 reads each buffer twice (once for the mean, once for the deviations); this
-kernel fuses both into one pass: each VMEM tile (R, BLOCK) produces its mean
-slice and accumulates its squared-deviation partial into a scalar."""
+kernel fuses both into one pass.  The buffer is tiled as (R, rows, lanes)
+VMEM blocks of its lane-dense view (``kernels/tiling.py``); each block
+writes its (rows, lanes) mean slice and adds its squared deviations, summed
+over the replicas, into a lane-dense (rows, lanes) f32 accumulator that
+stays resident across the grid.  The accumulator is reduced to the scalar
+after the call (the TPU compiler refuses scalar stores to VMEM)."""
 from __future__ import annotations
 
 import functools
@@ -13,21 +17,19 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-BLOCK = 1024
+from repro.kernels.tiling import lane_view
 
 
 def _mean_sqdev_kernel(w_ref, mean_ref, sq_ref):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
+    @pl.when((pl.program_id(0) == 0) & (pl.program_id(1) == 0))
     def _():
         sq_ref[...] = jnp.zeros_like(sq_ref)
 
-    w = w_ref[...].astype(jnp.float32)            # (R, BLOCK)
-    mean = jnp.mean(w, axis=0, keepdims=True)     # (1, BLOCK)
+    w = w_ref[...].astype(jnp.float32)            # (R, rows, lanes)
+    mean = jnp.mean(w, axis=0)                    # (rows, lanes)
     mean_ref[...] = mean.astype(mean_ref.dtype)
-    dev = w - mean
-    sq_ref[0, 0] += jnp.sum(dev * dev)
+    dev = w - mean[None]
+    sq_ref[...] += jnp.sum(dev * dev, axis=0)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -36,27 +38,20 @@ def mean_and_sqdev(w: jnp.ndarray, *, interpret: bool = False):
     w.shape[1:], Σ_i ||mean − w_i||² scalar f32).  Divide the scalar by R
     for the paper's S_k contribution."""
     R = w.shape[0]
-    inner = w.shape[1:]
-    flat = w.reshape(R, -1)
-    n = flat.shape[1]
-    pad = (-n) % BLOCK
-    if pad:
-        flat = jnp.concatenate(
-            [flat, jnp.zeros((R, pad), flat.dtype)], axis=1)
-    nb = flat.shape[1] // BLOCK
+    v = lane_view(w, lead=1)
+    rows, lanes = v.rows, v.lanes
     mean, sq = pl.pallas_call(
         _mean_sqdev_kernel,
-        grid=(nb,),
-        in_specs=[pl.BlockSpec((R, BLOCK), lambda i: (0, i))],
+        grid=v.grid,
+        in_specs=[pl.BlockSpec((R, rows, lanes), lambda i, j: (0, i, j))],
         out_specs=[
-            pl.BlockSpec((1, BLOCK), lambda i: (0, i)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
+            pl.BlockSpec((rows, lanes), lambda i, j: (i, j)),
+            pl.BlockSpec((rows, lanes), lambda i, j: (0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((1, flat.shape[1]), jnp.float32),
-            jax.ShapeDtypeStruct((1, 1), jnp.float32),
+            jax.ShapeDtypeStruct(v.x.shape[1:], jnp.float32),
+            jax.ShapeDtypeStruct((rows, lanes), jnp.float32),
         ],
         interpret=interpret,
-    )(flat)
-    mean = mean[0, :n].reshape(inner)
-    return mean, sq[0, 0]
+    )(v.x)
+    return v.restore(mean), jnp.sum(sq)

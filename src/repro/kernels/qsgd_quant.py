@@ -2,10 +2,14 @@
 
 The QSGD baseline's hot spot is a bandwidth-bound elementwise pass over
 every gradient buffer (quantize before transmit, dequantize after).  The
-kernels stream 8/128-aligned VMEM tiles; the tensor L2 norm is computed by
-a first reduction kernel, and the uniform randoms for stochastic rounding
-are supplied as an input stream so the kernel is bit-exactly testable
-against the jnp oracle."""
+kernels stream (rows, lanes) VMEM tiles of the buffer's lane-dense view
+(``kernels/tiling.py``), with rows a multiple of 32 so the int8 levels tile
+as well as the f32 inputs.  The tensor L2 norm comes from a first reduction
+kernel that accumulates a lane-dense (rows, lanes) partial, reduced after
+the call; the per-tensor scale then reaches the elementwise kernels as an
+SMEM scalar.  The uniform randoms for stochastic rounding are supplied as
+an input stream so the kernel is bit-exactly testable against the jnp
+oracle (``kernels/ref.py``) at the same norm."""
 from __future__ import annotations
 
 import functools
@@ -13,58 +17,52 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-BLOCK = 1024
+from repro.kernels.tiling import lane_view
+
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
+def _block(v):
+    return pl.BlockSpec((v.rows, v.lanes), lambda i, j: (i, j))
 
 
 def _sqsum_kernel(x_ref, o_ref):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
+    @pl.when((pl.program_id(0) == 0) & (pl.program_id(1) == 0))
     def _():
         o_ref[...] = jnp.zeros_like(o_ref)
 
     x = x_ref[...].astype(jnp.float32)
-    o_ref[0, 0] += jnp.sum(x * x)
+    o_ref[...] += x * x
 
 
-def _quant_kernel(x_ref, u_ref, norm_ref, lv_ref, *, s: int):
+def _quant_kernel(scale_ref, x_ref, u_ref, lv_ref):
     x = x_ref[...].astype(jnp.float32)
     u = u_ref[...].astype(jnp.float32)
-    norm = norm_ref[0, 0]
-    scaled = jnp.where(norm > 0, jnp.abs(x) * (s / norm), 0.0)
+    scaled = jnp.abs(x) * scale_ref[0, 0]
     floor = jnp.floor(scaled)
     mag = floor + (u < (scaled - floor)).astype(jnp.float32)
     lv_ref[...] = (jnp.sign(x) * mag).astype(jnp.int8)
 
 
-def _dequant_kernel(lv_ref, norm_ref, o_ref, *, s: int):
+def _dequant_kernel(step_ref, lv_ref, o_ref):
     o_ref[...] = (lv_ref[...].astype(jnp.float32)
-                  * (norm_ref[0, 0] / s)).astype(o_ref.dtype)
-
-
-def _pad_flat(x: jnp.ndarray, block: int):
-    flat = x.reshape(-1)
-    n = flat.shape[0]
-    pad = (-n) % block
-    if pad:
-        flat = jnp.concatenate([flat, jnp.zeros((pad,), flat.dtype)])
-    return flat.reshape(-1, block), n
+                  * step_ref[0, 0]).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def sqnorm(x: jnp.ndarray, *, interpret: bool = False) -> jnp.ndarray:
-    xb, _ = _pad_flat(x, BLOCK)
-    nb = xb.shape[0]
+    v = lane_view(x)
     out = pl.pallas_call(
         _sqsum_kernel,
-        grid=(nb,),
-        in_specs=[pl.BlockSpec((1, BLOCK), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, 1), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
+        grid=v.grid,
+        in_specs=[_block(v)],
+        out_specs=pl.BlockSpec((v.rows, v.lanes), lambda i, j: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((v.rows, v.lanes), jnp.float32),
         interpret=interpret,
-    )(xb)
-    return out[0, 0]
+    )(v.x)
+    return jnp.sum(out)
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "interpret"))
@@ -73,40 +71,31 @@ def quantize(x: jnp.ndarray, u: jnp.ndarray, *, bits: int = 8,
     """x: any-shape tensor; u: uniforms of the same shape.  Returns
     (levels int8 of x.shape, norm scalar f32)."""
     s = (1 << (bits - 1)) - 1
-    norm = jnp.sqrt(sqnorm(x, interpret=interpret)).reshape(1, 1)
-    xb, n = _pad_flat(x, BLOCK)
-    ub, _ = _pad_flat(u, BLOCK)
-    nb = xb.shape[0]
+    norm = jnp.sqrt(sqnorm(x, interpret=interpret))
+    scale = jnp.where(norm > 0, s / norm, 0.0).reshape(1, 1)
+    v, vu = lane_view(x), lane_view(u)
     lv = pl.pallas_call(
-        functools.partial(_quant_kernel, s=s),
-        grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((1, BLOCK), lambda i: (i, 0)),
-            pl.BlockSpec((1, BLOCK), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, BLOCK), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nb, BLOCK), jnp.int8),
+        _quant_kernel,
+        grid=v.grid,
+        in_specs=[_SMEM, _block(v), _block(v)],
+        out_specs=_block(v),
+        out_shape=jax.ShapeDtypeStruct(v.x.shape, jnp.int8),
         interpret=interpret,
-    )(xb, ub, norm)
-    return lv.reshape(-1)[:n].reshape(x.shape), norm[0, 0]
+    )(scale, v.x, vu.x)
+    return v.restore(lv), norm
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "interpret"))
 def dequantize(levels: jnp.ndarray, norm: jnp.ndarray, *, bits: int = 8,
                interpret: bool = False) -> jnp.ndarray:
     s = (1 << (bits - 1)) - 1
-    lb, n = _pad_flat(levels, BLOCK)
-    nb = lb.shape[0]
+    v = lane_view(levels)
     out = pl.pallas_call(
-        functools.partial(_dequant_kernel, s=s),
-        grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((1, BLOCK), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, BLOCK), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nb, BLOCK), jnp.float32),
+        _dequant_kernel,
+        grid=v.grid,
+        in_specs=[_SMEM, _block(v)],
+        out_specs=_block(v),
+        out_shape=jax.ShapeDtypeStruct(v.x.shape, jnp.float32),
         interpret=interpret,
-    )(lb, norm.reshape(1, 1))
-    return out.reshape(-1)[:n].reshape(levels.shape)
+    )((norm / s).astype(jnp.float32).reshape(1, 1), v.x)
+    return v.restore(out)

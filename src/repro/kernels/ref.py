@@ -29,12 +29,16 @@ def attention_ref(q, k, v, *, causal: bool = True, window: int = 0):
     return o.reshape(B, Sq, H, d).astype(q.dtype)
 
 
-def quantize_ref(x, u, *, bits: int = 8):
-    """QSGD with externally-supplied uniforms (same contract as the kernel)."""
+def quantize_ref(x, u, *, bits: int = 8, norm=None):
+    """QSGD with externally-supplied uniforms (same contract as the kernel).
+    ``norm`` fixes the tensor norm instead of recomputing it: a kernel's
+    blocked norm reduction may round differently, and its levels are then
+    checked bit-exactly at its own norm."""
     s = (1 << (bits - 1)) - 1
     xf = x.astype(jnp.float32)
-    norm = jnp.sqrt(jnp.sum(jnp.square(xf)))
-    scaled = jnp.where(norm > 0, jnp.abs(xf) / norm * s, 0.0)
+    if norm is None:
+        norm = jnp.sqrt(jnp.sum(jnp.square(xf)))
+    scaled = jnp.abs(xf) * jnp.where(norm > 0, s / norm, 0.0)
     floor = jnp.floor(scaled)
     mag = floor + (u < (scaled - floor)).astype(jnp.float32)
     return (jnp.sign(xf) * mag).astype(jnp.int8), norm

@@ -8,7 +8,7 @@ benches must keep seeing 1 device.
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -25,6 +25,17 @@ def make_host_mesh(model_parallel: int = 1) -> Mesh:
     assert n % model_parallel == 0
     return jax.make_mesh((n // model_parallel, model_parallel),
                          ("data", "model"))
+
+
+def auto_axes(mesh: Mesh) -> Mesh:
+    """The same devices and axis names with every axis in GSPMD's
+    ``Auto`` mode (``jax.make_mesh`` defaults to ``Explicit``): the
+    shard_map programs name their manual axes, and the partitioner owns
+    the rest."""
+    if all(t == AxisType.Auto for t in mesh.axis_types):
+        return mesh
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
 
 
 def replica_axes_for(plan: str, multi_pod: bool):

@@ -1,7 +1,13 @@
 """End-to-end training driver.
 
     PYTHONPATH=src python -m repro.launch.train --arch olmo-1b --method adpsgd \
-        --steps 200 --replicas 4 --reduced --backend vmap
+        --steps 200 --replicas 4 --backend vmap
+
+runs the smoke-size model (``--reduced``, the default: d_model <= 128, two
+layers).  ``--no-reduced`` runs the config's published widths;
+``--layers N`` then keeps the first N of its published layers, the one cut
+that fits a model onto fewer chips (the run prints it beside the published
+depth).
 
 ``--method`` accepts any name registered in ``repro/strategies`` (the five
 paper methods plus hier_adpsgd, qsgd_periodic, adacomm, dasgd, and anything
@@ -17,6 +23,7 @@ parallelism inside each replica — DESIGN.md §5 "Placements");
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import os
 import time
@@ -28,6 +35,7 @@ from repro.backends import available_backends, make_backend
 from repro.checkpoint.io import save_checkpoint, strategy_state
 from repro.configs import AveragingConfig, get_config, reduced
 from repro.data.pipeline import SyntheticTokens
+from repro.launch.cache import use_compile_cache
 from repro.launch.steps import make_loss_fn
 from repro.models import model as M
 from repro.optim import get_optimizer, make_lr_schedule
@@ -36,7 +44,9 @@ from repro.runtime.engine import Checkpointer, PeriodicEval, TrainerEngine
 from repro.strategies import available_strategies, make_strategy
 
 
-def main():
+def main(argv=None) -> TrainerEngine:
+    """Parse ``argv`` (default: the command line), train, print the
+    summary, and return the engine (history, final state, backend)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="olmo-1b")
     ap.add_argument("--method", default="adpsgd",
@@ -82,7 +92,13 @@ def main():
     ap.add_argument("--replicas", type=int, default=4)
     ap.add_argument("--batch", type=int, default=4, help="per-replica batch")
     ap.add_argument("--seq", type=int, default=128)
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="smoke-size widths (configs.base.reduced); "
+                         "--no-reduced runs the published widths")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="keep the first N layers of the model (0 = all); "
+                         "a cut of depth only, to fit fewer chips")
     ap.add_argument("--p-init", type=int, default=2)
     ap.add_argument("--p-const", type=int, default=8)
     ap.add_argument("--warmup-sync", type=int, default=8)
@@ -104,10 +120,22 @@ def main():
                     help="periodic checkpoints keep the stacked replica "
                          "axis (resumable); --no-keep-replicas writes "
                          "replica-averaged export checkpoints")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    print(f"compile cache: {use_compile_cache()}")
 
     run = get_config(args.arch)
     cfg = reduced(run.model, max_seq_len=args.seq) if args.reduced else run.model
+    if args.layers:
+        if not 0 < args.layers <= cfg.n_layers:
+            ap.error(f"--layers must be in 1..{cfg.n_layers}")
+        cfg = dataclasses.replace(cfg, n_layers=args.layers)
+    print(f"[{cfg.name}] {'reduced' if args.reduced else 'published'} widths:"
+          f" d_model={cfg.d_model} heads={cfg.n_heads}x{cfg.head_dim()}"
+          f" kv_heads={cfg.n_kv_heads} d_ff={cfg.d_ff} ({cfg.mlp_type})"
+          f" vocab={cfg.vocab_size} tied={cfg.tie_embeddings};"
+          f" depth {cfg.n_layers} of {run.model.n_layers} published layers;"
+          f" params {cfg.param_dtype}, compute {cfg.compute_dtype};"
+          f" {args.replicas} replicas x {args.batch} seq x {args.seq} tokens")
     avg_cfg = AveragingConfig(
         method=args.method, p_init=args.p_init, p_const=args.p_const,
         warmup_full_sync_steps=args.warmup_sync, k_sample_frac=0.25,
@@ -159,6 +187,7 @@ def main():
         avg_cfg=avg_cfg, total_steps=args.steps, strategy=strategy,
         backend=backend, clock=clock, callbacks=callbacks,
         track_variance_every=max(1, args.steps // 50), seed=args.seed)
+    del params0                 # the engine holds the stacked replicas
     t0 = time.time()
     hist = engine.run()
     dt = time.time() - t0
@@ -203,6 +232,7 @@ def main():
                        "variance_steps": hist.variance_steps,
                        "timing": hist.timing}, f)
         print(f"  history -> {args.out}")
+    return engine
 
 
 if __name__ == "__main__":

@@ -61,6 +61,22 @@ def test_qsgd_quantize(n, bits):
     assert float(jnp.max(jnp.abs(dq - x))) <= float(nm) / s + 1e-6
 
 
+@pytest.mark.parametrize("shape", [(64, 256), (2, 32, 384), (3, 40, 1030)])
+def test_qsgd_quantize_views(shape):
+    """Lane-dense views (last dim a multiple of 128, rows of 32) and a
+    padded flat view round like the oracle."""
+    k = jax.random.fold_in(KEY, sum(shape))
+    x = jax.random.normal(k, shape)
+    u = jax.random.uniform(jax.random.fold_in(k, 1), shape)
+    lv, nm = quantize(x, u, interpret=True)
+    lr, nr = ref.quantize_ref(x, u)
+    np.testing.assert_array_equal(np.asarray(lv), np.asarray(lr))
+    np.testing.assert_allclose(nm, nr, rtol=1e-6)
+    np.testing.assert_array_equal(
+        np.asarray(dequantize(lv, nm, interpret=True)),
+        np.asarray(ref.dequantize_ref(lv, nm)))
+
+
 def test_qsgd_multidim_and_zero():
     x = jax.random.normal(KEY, (33, 17))
     u = jax.random.uniform(jax.random.fold_in(KEY, 3), (33, 17))
@@ -72,8 +88,11 @@ def test_qsgd_multidim_and_zero():
     assert int(jnp.abs(lvz).max()) == 0
 
 
-@pytest.mark.parametrize("R,shape", [(2, (100,)), (8, (33, 7)), (16, (1024,)),
-                                     (4, (5, 4, 3))])
+@pytest.mark.parametrize("R,shape", [
+    (2, (100,)), (8, (33, 7)), (16, (1024,)), (4, (5, 4, 3)),
+    (2, (40, 1030)),      # flattened: 41200 elements pad to a whole tile
+    (16, (64, 2048)),     # lane-dense view, a (2, 2) grid of blocks
+])
 def test_param_variance(R, shape):
     w = jax.random.normal(jax.random.fold_in(KEY, R), (R,) + shape)
     m, sq = mean_and_sqdev(w, interpret=True)

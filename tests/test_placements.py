@@ -43,13 +43,6 @@ STEPS = 16
 REPLICAS = 8
 
 
-def _abstract_mesh(sizes, names):
-    try:
-        return AbstractMesh(tuple(zip(names, sizes)))
-    except TypeError:
-        return AbstractMesh(sizes, names)
-
-
 @pytest.fixture(scope="module")
 def setup8():
     data = SyntheticImages(n_samples=256, seed=0)
@@ -217,7 +210,7 @@ def test_hier_group_size_derived_from_pod_axis():
     """On a 2-pod dry-run mesh, hier_adpsgd's unset group_size resolves to
     replicas-per-pod and the device groups tile the innermost ('data')
     axis — inner syncs never cross the pod boundary."""
-    mesh = _abstract_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = AbstractMesh((2, 2, 2), ("pod", "data", "model"))
     b = MeshBackend(mesh=mesh, placement="replica_tp")
     b.bind(8)
     assert b.replica_axes == ("pod", "data")
@@ -228,7 +221,7 @@ def test_hier_group_size_derived_from_pod_axis():
     with pytest.raises(NotImplementedError, match="tile"):
         b._device_groups(4)                      # would span the pod axis
     # single-pod meshes have no natural boundary -> strategy heuristic
-    b1 = MeshBackend(mesh=_abstract_mesh((4, 2), ("data", "model")))
+    b1 = MeshBackend(mesh=AbstractMesh((4, 2), ("data", "model")))
     b1.bind(8)
     assert b1.default_group_size() is None
 

@@ -136,13 +136,6 @@ def test_optimizers_reduce_quadratic(R, dim, rnd):
 # ---------------------------------------------------------------------------
 
 
-def _abstract_mesh(sizes, names):
-    try:
-        return AbstractMesh(tuple(zip(names, sizes)))
-    except TypeError:
-        return AbstractMesh(sizes, names)
-
-
 def _check_spec_valid(spec, shape, mesh):
     """GSPMD validity: named axes exist, appear at most once across the
     spec, and divide the dim they shard."""
@@ -171,7 +164,7 @@ _PATHS_2D = ["embed", "lm_head", "wq|w", "wo|w", "w_up|w", "w_down|w",
        stf.integers(1, 4099), stf.integers(1, 515),
        stf.sampled_from([2, 3, 4, 8, 16]))
 def test_base_spec_divisibility_guard(path, d0, d1, m):
-    mesh = _abstract_mesh((4, m), ("data", "model"))
+    mesh = AbstractMesh((4, m), ("data", "model"))
     plan = ParallelismPlan(plan="replica_dp", placement="replica_tp")
     spec = sh.base_spec(ModelConfig(), path, (d0, d1), mesh, plan)
     _check_spec_valid(spec, (d0, d1), mesh)
@@ -184,7 +177,7 @@ def test_base_spec_divisibility_guard(path, d0, d1, m):
 def test_vocab_parallel_embed_falls_back(vocab, m):
     """Odd vocab sizes fall back from vocab-parallel to d-model sharding
     (and to replication when d_model is odd too)."""
-    mesh = _abstract_mesh((4, m), ("data", "model"))
+    mesh = AbstractMesh((4, m), ("data", "model"))
     plan = ParallelismPlan(plan="replica_dp")
     d_model = 8 * m
     spec = sh.base_spec(ModelConfig(), "embed", (vocab, d_model), mesh, plan)
@@ -204,8 +197,8 @@ def test_param_specs_always_valid_for_mesh(R_pow, d0, d1, m, two_pod):
     R = 4 * R_pow      # replica-axis divisibility is bind()'s runtime guard,
     #                    not param_specs' — keep R a multiple of the 4
     #                    replica devices both meshes have
-    mesh = (_abstract_mesh((2, 2, m), ("pod", "data", "model")) if two_pod
-            else _abstract_mesh((4, m), ("data", "model")))
+    mesh = (AbstractMesh((2, 2, m), ("pod", "data", "model")) if two_pod
+            else AbstractMesh((4, m), ("data", "model")))
     rep = ("pod", "data") if two_pod else ("data",)
     tree = {"fc1": {"w": np.zeros((R, d0, d1)), "b": np.zeros((R, d1))},
             "odd": {"w": np.zeros((R, d0))}}

@@ -14,17 +14,8 @@ ARCHS = ["qwen2-vl-2b", "xlstm-350m", "whisper-medium", "qwen2.5-14b",
          "olmo-1b", "glm4-9b", "mixtral-8x22b", "jamba-1.5-large-398b",
          "deepseek-v2-lite-16b", "minicpm-2b"]
 
-# jax >= 0.4.36 constructs AbstractMesh from ((name, size), ...) pairs;
-# older versions took (sizes, names) positionally.
-def _abstract_mesh(sizes, names):
-    try:
-        return AbstractMesh(tuple(zip(names, sizes)))
-    except TypeError:
-        return AbstractMesh(sizes, names)
-
-
-MESH_1POD = _abstract_mesh((16, 16), ("data", "model"))
-MESH_2POD = _abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+MESH_1POD = AbstractMesh((16, 16), ("data", "model"))
+MESH_2POD = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def _axis_sizes(mesh):
