@@ -1,0 +1,10 @@
+"""Puts the benchmark's own directory and the trainer's ``src`` on the
+import path of the benchmark's tests."""
+import sys
+from pathlib import Path
+
+BENCH = Path(__file__).resolve().parents[1]
+ROOT = BENCH.parent
+for p in (str(ROOT / "src"), str(BENCH)):
+    if p not in sys.path:
+        sys.path.insert(0, p)
