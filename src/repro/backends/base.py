@@ -22,7 +22,10 @@ The descriptor carries the collective kind, wire format, group, and overlap
 hint; lowering resolves ``op.name`` to the backend's ``_lower_<name>``
 builder and wraps the compiled program so every invocation is priced *from
 the descriptor itself* (``op.wire_bytes``) into the bound telemetry clock —
-the old hand-synchronized ``PROGRAM_COMM`` table is gone.  Ops with
+the old hand-synchronized ``PROGRAM_COMM`` table is gone.  Every call also
+runs inside the profiler span ``repro.program.<op.name>`` (stats ``step``
+and ``bytes``), and every program compiles to the XLA module
+``jit_<op.name>``, so a profile names each device run after its op.  Ops with
 ``overlap=True`` dispatch asynchronously and return an ``InFlightOp``
 handle fetched later (DaSGD's delayed correction).
 
@@ -42,9 +45,11 @@ on the train driver selects one.
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, Dict, List, Optional, Type
 
 import jax
+from jax.profiler import TraceAnnotation
 
 from repro.backends import ops as collective_ops
 from repro.backends.ops import CollectiveOp, InFlightOp
@@ -76,6 +81,7 @@ class ExecutionBackend:
         self.use_kernel = bool(use_kernel)
         self.n_replicas: Optional[int] = None
         self.clock = None              # telemetry clock (runtime/clock.py)
+        self.step = 0                  # engine iteration, stamped on spans
 
     # ------------------------------------------------------------- topology
     def bind(self, n_replicas: int) -> None:
@@ -98,38 +104,54 @@ class ExecutionBackend:
         self.clock = clock
 
     def timed(self, op: CollectiveOp, fn: Callable) -> Callable:
-        """Wrap a compiled program so each invocation reports one
-        ``(compute_s, comm_s, bytes)`` record into the bound clock's
-        ``Timeline``.  The communication shape comes solely from the op
-        descriptor: bytes are ``op.wire_bytes`` of the per-replica
-        parameter count (read off the stacked operand per invocation, so
-        one wrapper serves every shape), the collective kind and group
-        ride the op, and ``overlap=True`` ops dispatch asynchronously —
-        the wrapper returns an ``InFlightOp`` whose ``fetch()`` settles
-        the exchange with the clock later."""
+        """Wrap a compiled program so each invocation runs inside the
+        profiler span ``repro.program.<op.name>`` and, with a clock bound,
+        reports one ``(compute_s, comm_s, bytes)`` record into its
+        ``Timeline``.  The span carries the stats ``step`` (the engine
+        iteration, ``self.step``) and ``bytes``: ``op.wire_bytes`` of the
+        per-replica parameter count read off the stacked first operand —
+        the exchange's communication cost, 0 for collective-free ops —
+        worked out once per operand structure.  The collective kind and
+        group ride the op, and ``overlap=True`` ops dispatch
+        asynchronously: the wrapper returns an ``InFlightOp`` whose
+        ``fetch()`` settles the exchange (and the clock) later."""
+        span = f"repro.program.{op.name}"
+        priced: Dict[Any, tuple] = {}
 
-        def wrapped(*args):
-            clock = self.clock
-            if clock is None:
-                out = fn(*args)
-                return InFlightOp(op, out) if op.overlap else out
+        def wire(tree):
+            """(bytes, nodes) of one invocation on operand ``tree``."""
             n = self.n_replicas or 1
-            nbytes = 0.0
-            if op.collective is not None:
+            if op.collective is None:
+                return 0.0, n
+            leaves, treedef = jax.tree_util.tree_flatten(tree)
+            key = (treedef, tuple(x.shape for x in leaves), n)
+            got = priced.get(key)
+            if got is None:
                 if op.group:
                     n = int(op.group)
-                leaves = jax.tree_util.tree_leaves(args[0])
                 n_params = (sum(x.size for x in leaves)
                             // max(1, self.n_replicas or 1))
-                nbytes = op.wire_bytes(n_params, n, n_tensors=len(leaves))
-            if op.overlap:
-                out, rec = clock.dispatch_async(
-                    op.name, fn, args, comm_bytes=nbytes,
-                    collective=op.collective, n_nodes=n)
-                return InFlightOp(op, out, clock, rec)
-            return clock.measure(op.name, fn, args, is_step=op.is_step,
-                                 comm_bytes=nbytes, collective=op.collective,
-                                 n_nodes=n)
+                got = priced[key] = (
+                    op.wire_bytes(n_params, n, n_tensors=len(leaves)), n)
+            return got
+
+        def wrapped(*args):
+            nbytes, n = wire(args[0])
+            with TraceAnnotation(span, step=self.step, bytes=nbytes):
+                clock = self.clock
+                if clock is None:
+                    out = fn(*args)
+                    return (InFlightOp(op, out, step=lambda: self.step)
+                            if op.overlap else out)
+                if op.overlap:
+                    out, rec = clock.dispatch_async(
+                        op.name, fn, args, comm_bytes=nbytes,
+                        collective=op.collective, n_nodes=n)
+                    return InFlightOp(op, out, clock, rec,
+                                      step=lambda: self.step)
+                return clock.measure(op.name, fn, args, is_step=op.is_step,
+                                     comm_bytes=nbytes,
+                                     collective=op.collective, n_nodes=n)
 
         wrapped.__wrapped__ = fn       # the compiled program, for inspection
         return wrapped
@@ -249,7 +271,20 @@ class ExecutionBackend:
                 lambda w, d: (w.astype(jnp.float32) + d).astype(w.dtype),
                 W, delta)
 
-        return jax.jit(apply, donate_argnums=donated(0, 1))
+        return jax.jit(named(op.name, apply), donate_argnums=donated(0, 1))
+
+
+def named(name: str, fn: Callable) -> Callable:
+    """``fn`` under the name ``name``: ``jax.jit`` calls the XLA module it
+    compiles ``jit_<name>``, so a profile shows which program ran.  The
+    traced computation is ``fn``'s own."""
+
+    @functools.wraps(fn)
+    def program(*args):
+        return fn(*args)
+
+    program.__name__ = program.__qualname__ = name
+    return program
 
 
 def donated(*argnums: int):

@@ -51,7 +51,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.backends.base import ExecutionBackend, register_backend
+from repro.backends.base import ExecutionBackend, named, register_backend
 from repro.configs.base import ModelConfig, ParallelismPlan
 from repro.core import averaging as avg
 from repro.core import qsgd as qsgd_mod
@@ -220,15 +220,16 @@ class MeshBackend(ExecutionBackend):
             fn = self._cache[key] = build()
         return fn
 
-    def _shmap(self, chunk, in_specs, out_specs, out_shardings=None, *,
-               manual=None):
-        """``manual=None`` takes the placement's manual axes (the replica
-        axes under replica_tp, GSPMD owning 'model'); pass the full axis
-        set to force a fully-manual region."""
-        fn = jax.shard_map(chunk, mesh=self.mesh, in_specs=in_specs,
-                           out_specs=out_specs, check_vma=False,
-                           axis_names=self._manual if manual is None
-                           else manual)
+    def _shmap(self, name, chunk, in_specs, out_specs, out_shardings=None,
+               *, manual=None):
+        """The jitted ``shard_map`` of ``chunk``, compiled to the XLA module
+        ``jit_<name>``.  ``manual=None`` takes the placement's manual axes
+        (the replica axes under replica_tp, GSPMD owning 'model'); pass the
+        full axis set to force a fully-manual region."""
+        fn = named(name, jax.shard_map(
+            chunk, mesh=self.mesh, in_specs=in_specs, out_specs=out_specs,
+            check_vma=False,
+            axis_names=self._manual if manual is None else manual))
         if out_shardings is not None:
             return jax.jit(fn, out_shardings=out_shardings)
         return jax.jit(fn)
@@ -263,8 +264,8 @@ class MeshBackend(ExecutionBackend):
         """Replica mean of stacked per-replica metrics — a separate tiny
         program, so the cross-replica round never rides the step's HLO
         (the engine reads the scalar back each iteration anyway)."""
-        fn = self._cached("metrics_mean", (metrics,), lambda: jax.jit(
-            lambda m: _tm(lambda x: jnp.mean(x, axis=0), m)))
+        fn = self._cached("metrics_mean", (metrics,), lambda: jax.jit(named(
+            "metrics_mean", lambda m: _tm(lambda x: jnp.mean(x, axis=0), m))))
         return fn(metrics)
 
     # ------------------------------------------------------------ lowerings
@@ -282,7 +283,7 @@ class MeshBackend(ExecutionBackend):
 
         def prog(W, opt_state, batch, lr):
             fn = self._cached("step", (W, opt_state, batch), lambda: self._shmap(
-                chunk,
+                op.name, chunk,
                 (self._stacked(W), self._stacked(opt_state),
                  self._stacked(batch), P()),
                 (self._stacked(W), self._stacked(opt_state), P(self._entry)),
@@ -309,7 +310,7 @@ class MeshBackend(ExecutionBackend):
 
         def prog(W, opt_state, batch, lr):
             fn = self._cached("full", (W, opt_state, batch), lambda: self._shmap(
-                chunk,
+                op.name, chunk,
                 (self._stacked(W), self._stacked(opt_state),
                  self._stacked(batch), P()),
                 (self._stacked(W), self._stacked(opt_state), P()),
@@ -346,7 +347,7 @@ class MeshBackend(ExecutionBackend):
             # computes whole on its model devices; the TP layout is pinned
             # back on the outputs.
             fn = self._cached("qsgd", (W, opt_state, batch), lambda: self._shmap(
-                chunk,
+                op.name, chunk,
                 (self._stacked(W), self._stacked(opt_state),
                  self._stacked(batch), P(), P()),
                 (self._stacked(W), self._stacked(opt_state), P()),
@@ -373,7 +374,8 @@ class MeshBackend(ExecutionBackend):
             fn = self._cached(
                 f"all_mean{int(sync_momentum)}", (W, opt_state),
                 lambda: self._shmap(
-                    chunk, (self._stacked(W), self._stacked(opt_state)),
+                    op.name, chunk,
+                    (self._stacked(W), self._stacked(opt_state)),
                     (self._stacked(W), self._stacked(opt_state), P()),
                     out_shardings=self._pin(
                         lambda: self._param_shardings(W),
@@ -395,7 +397,7 @@ class MeshBackend(ExecutionBackend):
             # and the rules are suffix-anchored, so buffers land on the
             # same TP layout put_opt gave them
             fn = self._cached("opt_mean", (opt_state,), lambda: self._shmap(
-                chunk, (self._stacked(opt_state),),
+                op.name, chunk, (self._stacked(opt_state),),
                 self._stacked(opt_state),
                 out_shardings=(self._param_shardings(opt_state)
                                if self.placement == "replica_tp" else None)))
@@ -428,7 +430,7 @@ class MeshBackend(ExecutionBackend):
                     f"group_size={g} does not align with {r_local} local "
                     f"replicas per device")
             return self._shmap(
-                chunk, (self._stacked(W),), self._stacked(W),
+                op.name, chunk, (self._stacked(W),), self._stacked(W),
                 out_shardings=(self._param_shardings(W)
                                if self.placement == "replica_tp" else None))
 
@@ -497,7 +499,7 @@ class MeshBackend(ExecutionBackend):
             # paper prices) still carries only int8 levels + norms, and
             # out_shardings pins the TP layout right back
             fn = self._cached("qam", (W, anchor), lambda: self._shmap(
-                chunk,
+                op.name, chunk,
                 (self._stacked(W), self._replicated(anchor), P()),
                 (self._stacked(W), self._replicated(anchor), P()),
                 out_shardings=self._pin(
@@ -519,7 +521,7 @@ class MeshBackend(ExecutionBackend):
             # steps (DaSGD) — pin it to the TP layout so it never sits
             # model-replicated on the mesh
             fn = self._cached("mean_delta", (W,), lambda: self._shmap(
-                chunk, (self._stacked(W),), (self._stacked(W), P()),
+                op.name, chunk, (self._stacked(W),), (self._stacked(W), P()),
                 out_shardings=self._pin(lambda: self._param_shardings(W), None)))
             return fn(W)
 

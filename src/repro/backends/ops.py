@@ -32,7 +32,9 @@ program models — one truth, not three tables.
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Optional
+from typing import Callable, Optional
+
+from jax.profiler import TraceAnnotation
 
 # ---------------------------------------------------------------------------
 # Wire formats
@@ -175,19 +177,25 @@ class InFlightOp:
     fetched.  ``fetch()`` returns the program outputs, charging any
     remaining (un-overlapped) communication to the bound clock exactly
     once; jax's async dispatch keeps the device busy in between, so the
-    step path never blocked on the exchange."""
+    step path never blocked on the exchange.  Each fetch runs inside the
+    profiler span ``repro.program.<op.name>.fetch``, its ``step`` stat the
+    iteration that ``step()`` reports at fetch time."""
 
-    def __init__(self, op: CollectiveOp, outputs, clock=None, record=None):
+    def __init__(self, op: CollectiveOp, outputs, clock=None, record=None,
+                 step: Callable[[], int] = lambda: 0):
         self.op = op
         self._outputs = outputs
         self._clock = clock
         self._record = record
+        self._step = step
         self.fetched = False
 
     def fetch(self):
-        if not self.fetched:
-            self.fetched = True
-            if self._clock is not None:
-                self._clock.complete_async(self.op.name, self._record,
-                                           self._outputs)
-        return self._outputs
+        with TraceAnnotation(f"repro.program.{self.op.name}.fetch",
+                             step=self._step()):
+            if not self.fetched:
+                self.fetched = True
+                if self._clock is not None:
+                    self._clock.complete_async(self.op.name, self._record,
+                                               self._outputs)
+            return self._outputs

@@ -20,7 +20,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.backends.base import ExecutionBackend, donated, register_backend
+from repro.backends.base import (ExecutionBackend, donated, named,
+                                 register_backend)
 from repro.core import averaging as avg
 from repro.core import qsgd as qsgd_mod
 
@@ -45,33 +46,34 @@ class VmapBackend(ExecutionBackend):
     # ------------------------------------------------------------ lowerings
     # resolved by ExecutionBackend.lower(op); every compiled program comes
     # back through timed(op, ...), so a bound clock prices each invocation
-    # from the op descriptor (backends/base.py)
+    # from the op descriptor (backends/base.py); each is named after its op
+    # (named), so it compiles to the XLA module jit_<op.name>
     # W (and the optimizer state of a step) are dead once the program
     # returns their successors: donating them keeps one copy of the
     # replica stack on the device, which full-width models need
     def _lower_replica_step(self, op, *, loss_fn, optimizer):
-        return jax.jit(avg.make_local_step(loss_fn, optimizer),
+        return jax.jit(named(op.name, avg.make_local_step(loss_fn, optimizer)),
                        donate_argnums=donated(0, 1))
 
     def _lower_full_step(self, op, *, loss_fn, optimizer):
-        return jax.jit(avg.make_full_step(loss_fn, optimizer))
+        return jax.jit(named(op.name, avg.make_full_step(loss_fn, optimizer)))
 
     def _lower_qsgd_step(self, op, *, loss_fn, optimizer):
-        return jax.jit(
-            qsgd_mod.make_qsgd_step(loss_fn, optimizer, op.wire.bits))
+        return jax.jit(named(op.name, qsgd_mod.make_qsgd_step(
+            loss_fn, optimizer, op.wire.bits)))
 
     def _lower_all_mean(self, op, *, sync_momentum=False):
         use_kernel = self.use_kernel
-        return jax.jit(lambda W, o: avg.sync_replicas(
-            W, o, sync_momentum=sync_momentum, use_kernel=use_kernel),
+        return jax.jit(named(op.name, lambda W, o: avg.sync_replicas(
+            W, o, sync_momentum=sync_momentum, use_kernel=use_kernel)),
             donate_argnums=donated(0))
 
     def _lower_inner_mean(self, op):
         g = op.group
-        return jax.jit(lambda W: avg.group_sync(W, g))
+        return jax.jit(named(op.name, lambda W: avg.group_sync(W, g)))
 
     def _lower_opt_mean(self, op):
-        return jax.jit(avg.sync_opt_state)
+        return jax.jit(named(op.name, avg.sync_opt_state))
 
     def _lower_quantized_all_mean(self, op):
         """Byte-true QSGD-quantized parameter deltas from a shared
@@ -85,7 +87,6 @@ class VmapBackend(ExecutionBackend):
         bits = op.wire.bits
         use_kernel = jax.default_backend() == "tpu"
 
-        @jax.jit
         def qsync(W, anchor, key):
             R = jax.tree_util.tree_leaves(W)[0].shape[0]
             delta = jax.tree_util.tree_map(
@@ -109,10 +110,9 @@ class VmapBackend(ExecutionBackend):
                 W, new_anchor)
             return W_new, new_anchor, s_k
 
-        return qsync
+        return jax.jit(named(op.name, qsync))
 
     def _lower_mean_delta(self, op):
-        @jax.jit
         def delta(W):
             means = jax.tree_util.tree_map(
                 lambda x: jnp.mean(x.astype(jnp.float32), axis=0,
@@ -125,4 +125,4 @@ class VmapBackend(ExecutionBackend):
                 lambda x, m: m - x.astype(jnp.float32), W, means)
             return d, s_k
 
-        return delta
+        return jax.jit(named(op.name, delta))

@@ -24,16 +24,24 @@ control path stays as lean as the seed loop's.
 
 RNG keys are derived statelessly (``fold_in(base, k); fold_in(·, j)``), so a
 checkpoint-resumed run replays the identical key stream from any step.
+
+Every iteration is a profiler step span ``repro.iteration`` holding the spans
+``repro.input`` (the ``data_fn`` call), ``repro.keys`` (the key derivation),
+``repro.program.<op>`` (each program call, ``backends/base.py``),
+``repro.readback.loss`` / ``repro.readback.s_k`` (the host reads of the loss
+and the sync probe) and ``repro.callback.<class>`` (each callback call); each
+carries the stat ``step=k``.  They cost about a microsecond each when no
+profiler trace is active (DESIGN.md §6, "Tracing").
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, Iterable, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.backends import ExecutionBackend, resolve_backend
 from repro.configs.base import AveragingConfig
@@ -58,7 +66,6 @@ class TrainHistory:
     lr_start_step: int = 0        # absolute step of lrs[0] (resumed runs)
     evals: List[Dict[str, float]] = field(default_factory=list)
     eval_steps: List[int] = field(default_factory=list)
-    wall_s: float = 0.0
     n_syncs: int = 0
     # telemetry (runtime/clock.py): Timeline.summary() of the run when the
     # engine carried a clock — measured (wall) or simulated per-program
@@ -292,80 +299,92 @@ class TrainerEngine:
         hist = self.history
         if not hist.lrs:
             hist.lr_start_step = start_step
-        t0 = time.time()
         tl = self.timeline
         # a sampled WallClock asks to keep the dispatch pipeline async:
         # per-step float(loss) read-back would re-sync it every iteration,
         # so losses stay device scalars until run end (values identical)
         defer_loss = bool(getattr(self.clock, "defer_loss_readback", False))
 
-        def record_sync(at, lr_at, s_val, timing):
+        def callback(hook: str, k: int, *args):
+            for cb in self.callbacks:
+                with TraceAnnotation(f"repro.callback.{type(cb).__name__}",
+                                     step=k):
+                    getattr(cb, hook)(self, *args)
+
+        def record_sync(k, at, lr_at, s_val, timing):
             """One sync event into history + controller + callbacks —
             shared by the immediate ("s_k") and the overlapped-settlement
             ("s_k_at") paths so they can never drift apart."""
-            s_k = float(s_val)
+            with TraceAnnotation("repro.readback.s_k", step=k):
+                s_k = float(s_val)
             self.strategy.observe(at, lr_at, s_k)
             hist.s_k.append(s_k)
             hist.sync_steps.append(at)
             hist.period_history.append(self.strategy.period)
-            for cb in self.callbacks:
-                cb.on_sync(self, at, s_k, timing)
+            callback("on_sync", k, at, s_k, timing)
 
         for k in range(start_step, stop):
-            lr = self.lr_fn(k)
-            hist.lrs.append(lr)
-            batch = self.data_fn(k)
-            step_key = jax.random.fold_in(self._base_key, k)
-            step_info: Dict[str, Any] = {}
-            if tl is not None:
-                tl.step = k          # dispatches below stamp this iteration
-            for j, action in enumerate(self.strategy.actions(k)):
-                key = jax.random.fold_in(step_key, j)
-                self.W, self.opt_state, info = self.strategy.dispatch(
-                    action, self.W, self.opt_state, batch, lr, key)
-                timing = tl.last if tl is not None else None
-                if "loss" in info:
-                    step_info = info
-                    loss_val = (info["loss"] if defer_loss
-                                else float(info["loss"]))
-                    hist.losses.append(loss_val)
-                    self.strategy.observe_loss(k, loss_val)
-                    if timing is not None:
-                        info["timing"] = timing
-                    for cb in self.callbacks:
-                        cb.on_step_end(self, k, info)
-                if "s_k" in info:
-                    record_sync(k, lr, info["s_k"], timing)
-                if "s_k_at" in info:
-                    # an overlapped sync settled: the probe belongs to the
-                    # snapshot iteration, not the fetch iteration — there
-                    # is at most one exchange in flight (delay < period),
-                    # so ordering within the history is preserved
-                    at, s_val = info["s_k_at"]
-                    at = int(at)
-                    if tl is not None:
-                        # on_sync's contract is the *exchange's* record
-                        # (comm_s/bytes), which was written at dispatch —
-                        # not the apply program's that tl.last holds now
-                        timing = next(
-                            (r for r in reversed(tl.records)
-                             if r.overlap and r.step == at), timing)
-                    record_sync(at, self.lr_fn(at), s_val, timing)
-                if info.get("inner_sync"):
-                    hist.inner_sync_steps.append(k)
-            for cb in self.callbacks:
-                cb.on_iteration_end(self, k, step_info)
+            with StepTraceAnnotation("repro.iteration", step_num=k, step=k):
+                lr = self.lr_fn(k)
+                hist.lrs.append(lr)
+                with TraceAnnotation("repro.input", step=k):
+                    batch = self.data_fn(k)
+                with TraceAnnotation("repro.keys", step=k):
+                    step_key = jax.random.fold_in(self._base_key, k)
+                step_info: Dict[str, Any] = {}
+                if tl is not None:
+                    tl.step = k      # dispatches below stamp this iteration
+                self.backend.step = k
+                for j, action in enumerate(self.strategy.actions(k)):
+                    with TraceAnnotation("repro.keys", step=k):
+                        key = jax.random.fold_in(step_key, j)
+                    self.W, self.opt_state, info = self.strategy.dispatch(
+                        action, self.W, self.opt_state, batch, lr, key)
+                    timing = tl.last if tl is not None else None
+                    if "loss" in info:
+                        step_info = info
+                        if defer_loss:
+                            loss_val = info["loss"]
+                        else:
+                            with TraceAnnotation("repro.readback.loss",
+                                                 step=k):
+                                loss_val = float(info["loss"])
+                        hist.losses.append(loss_val)
+                        self.strategy.observe_loss(k, loss_val)
+                        if timing is not None:
+                            info["timing"] = timing
+                        callback("on_step_end", k, k, info)
+                    if "s_k" in info:
+                        record_sync(k, k, lr, info["s_k"], timing)
+                    if "s_k_at" in info:
+                        # an overlapped sync settled: the probe belongs to
+                        # the snapshot iteration, not the fetch iteration —
+                        # there is at most one exchange in flight (delay <
+                        # period), so ordering within the history is
+                        # preserved
+                        at, s_val = info["s_k_at"]
+                        at = int(at)
+                        if tl is not None:
+                            # on_sync's contract is the *exchange's* record
+                            # (comm_s/bytes), which was written at dispatch
+                            # — not the apply program's that tl.last holds
+                            timing = next(
+                                (r for r in reversed(tl.records)
+                                 if r.overlap and r.step == at), timing)
+                        record_sync(k, at, self.lr_fn(at), s_val, timing)
+                    if info.get("inner_sync"):
+                        hist.inner_sync_steps.append(k)
+                callback("on_iteration_end", k, k, step_info)
         if defer_loss:
-            hist.losses[:] = [float(v) for v in hist.losses]
-        hist.wall_s += time.time() - t0
+            with TraceAnnotation("repro.readback.loss", step=stop - 1):
+                hist.losses[:] = [float(v) for v in hist.losses]
         hist.n_syncs = self.strategy.n_comm_events - self._comm_event_base
         if tl is not None:
             hist.timing = dict(tl.summary(), clock=self.clock.kind,
                                sim_wall_s=self.clock.now())
         hist.final_W = self.W
         hist.final_opt = self.opt_state
-        for cb in self.callbacks:
-            cb.on_run_end(self)
+        callback("on_run_end", stop - 1)
         return hist
 
 
