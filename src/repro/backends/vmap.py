@@ -48,9 +48,12 @@ class VmapBackend(ExecutionBackend):
     # back through timed(op, ...), so a bound clock prices each invocation
     # from the op descriptor (backends/base.py); each is named after its op
     # (named), so it compiles to the XLA module jit_<op.name>
-    # W (and the optimizer state of a step) are dead once the program
-    # returns their successors: donating them keeps one copy of the
-    # replica stack on the device, which full-width models need
+    # W and the optimizer state are dead once a step or a sync returns
+    # their successors: donating them keeps one copy of the replica stack
+    # on the device, which full-width models need.  The sync donates the
+    # state too though it mostly hands it back unchanged (sync_momentum
+    # off): an undonated pass-through is a full copy of m and v per sync,
+    # and allocating room for it makes the allocator defragment
     def _lower_replica_step(self, op, *, loss_fn, optimizer):
         return jax.jit(named(op.name, avg.make_local_step(loss_fn, optimizer)),
                        donate_argnums=donated(0, 1))
@@ -66,7 +69,7 @@ class VmapBackend(ExecutionBackend):
         use_kernel = self.use_kernel
         return jax.jit(named(op.name, lambda W, o: avg.sync_replicas(
             W, o, sync_momentum=sync_momentum, use_kernel=use_kernel)),
-            donate_argnums=donated(0))
+            donate_argnums=donated(0, 1))
 
     def _lower_inner_mean(self, op):
         g = op.group
