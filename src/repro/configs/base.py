@@ -127,7 +127,6 @@ class ModelConfig:
     # --- numerics / compile ---
     param_dtype: str = "float32"
     compute_dtype: str = "bfloat16"
-    use_flash: bool = False       # Pallas flash attention (TPU); jnp path off-TPU
     remat: bool = True
     remat_policy: str = "nothing" # nothing(_saveable) | dots (dots_saveable)
     scan_layers: bool = True      # lax.scan over repeating layer groups

@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import MLAConfig, ModelConfig, MoEConfig
+from repro.kernels import ops as kops
 
 Params = Dict[str, Any]
 
@@ -199,6 +200,25 @@ def _causal_mask(q_pos: jnp.ndarray, k_pos: jnp.ndarray, window: int) -> jnp.nda
     return m
 
 
+def _flash_path(cfg: ModelConfig, seq_len: int) -> bool:
+    """Whether a full-sequence self-attention over ``seq_len`` tokens runs
+    as the flash kernels: on a TPU, for the plain causal mask (no sliding
+    window, no logit softcap), a shape the kernels' tiles divide, and a
+    program the kernels can lower in.  The kernels mask by token index,
+    which is the mask of the positions ``model.forward`` gives: 0 to S - 1.
+
+    GSPMD cannot partition a Mosaic kernel, so it lowers only in a program
+    on one device or inside a ``shard_map`` manual over every mesh axis.
+    A plain jit may span every device it sees, and a ``replica_tp`` program
+    leaves the ``model`` axis to GSPMD: both keep the plain core."""
+    mesh = jax.sharding.get_abstract_mesh()
+    lowers = (jax.device_count() == 1 if mesh.empty
+              else set(mesh.manual_axes) == set(mesh.axis_names))
+    return (jax.default_backend() == "tpu" and lowers
+            and cfg.sliding_window == 0 and not cfg.attn_logit_softcap
+            and kops.flash_block(seq_len, cfg.head_dim()) > 0)
+
+
 def attention_forward(p: Params, x: jnp.ndarray, cfg: ModelConfig, *,
                       positions: jnp.ndarray,
                       cache: Optional[Params] = None,
@@ -232,9 +252,8 @@ def attention_forward(p: Params, x: jnp.ndarray, cfg: ModelConfig, *,
         k = apply_rope(k, positions, cfg.rope_theta, cfg.partial_rotary_factor)
 
     if cache is None:
-        if cfg.use_flash and cfg.sliding_window == 0 and S > 1:
-            from repro.kernels import ops as kops
-            out = kops.flash_attention(q, k, v, causal=True)
+        if _flash_path(cfg, S):
+            out = kops.flash_attention(q, k, v)
         else:
             mask = _causal_mask(positions, positions, cfg.sliding_window)
             out = _sdpa(q, k, v, mask, cfg.attn_logit_softcap)

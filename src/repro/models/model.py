@@ -3,7 +3,7 @@ single-token decode against caches, and the LM loss.
 
 A "batch" is a dict with keys depending on the family:
   tokens        (B,S) int32                        — always
-  positions     (B,S) int32                        — optional (default arange)
+  positions     (B,1) int32                        — decode (default cache index)
   mrope_pos     (3,B,S) int32                      — vlm (M-RoPE)
   vision_embeds (B,P,D)                            — vlm patch-embedding stub
   frames        (B,T,D)                            — audio frontend stub
@@ -96,10 +96,7 @@ def _embed_inputs(params: Params, batch: Dict[str, jnp.ndarray],
         vis = batch["vision_embeds"].astype(cdt)
         x = jnp.concatenate([vis, x], axis=1)           # vision prefix
         S = x.shape[1]
-    if "positions" in batch:
-        pos = batch["positions"]
-    else:
-        pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
+    pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
     if cfg.pos_type == "mrope" and mrope is None:
         mrope = jnp.broadcast_to(pos[None], (3, B, S))  # text-only: t=h=w
     if cfg.pos_type == "learned":
